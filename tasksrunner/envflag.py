@@ -69,8 +69,6 @@ FLAGS: dict[str, Flag] = dict([
        secret=True),
     _f("TASKSRUNNER_APP_ID", "string", "unset",
        "app-id grants are evaluated against (injected by the orchestrator)"),
-    _f("TASKSRUNNER_BENCH_TPU_FORCE", "bool", "off",
-       "force the TPU benchmark sections to run even off-TPU"),
     _f("TASKSRUNNER_CHAOS", "bool", "off",
        "master gate for declarative fault injection (kind: Chaos)"),
     _f("TASKSRUNNER_FLASH", "bool", "on",

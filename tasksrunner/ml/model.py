@@ -16,12 +16,14 @@ TPU-first design notes:
   dimensions on ``tp``, sequence on ``sp``; XLA inserts the
   collectives (psum for tp-reduced matmuls, gradient all-reduce over
   dp) — nothing is hand-scheduled;
-* attention uses plain ``jnp.einsum`` so XLA can fuse QK^T → softmax
-  → V into its flash-style schedule on TPU — except under sequence
-  parallelism (an ``sp`` axis of size > 1), where the attention core
-  switches to ring attention (tasksrunner/ml/ring.py): K/V blocks
-  rotate by ``ppermute`` over the ICI ring and no device ever holds
-  the full sequence.
+* attention runs the Pallas flash kernel (tasksrunner/ml/flash.py),
+  under ``shard_map`` on a mesh because GSPMD cannot split a Mosaic
+  call, or the plain ``jnp.einsum`` pair with ``TASKSRUNNER_FLASH=0``
+  (the tests' reference) — except under sequence parallelism (an
+  ``sp`` axis of size > 1), where the attention core switches to ring
+  attention (tasksrunner/ml/ring.py): K/V blocks rotate by
+  ``ppermute`` over the ICI ring and no device ever holds the full
+  sequence.
 """
 
 from __future__ import annotations
@@ -47,6 +49,13 @@ class ModelConfig:
     @property
     def d_head(self) -> int:
         return self.d_model // self.n_heads
+
+
+#: the train-step shape measured on the chip (bench.py --tpu-bench) and
+#: smoke-tested there (chip_smoke.py); the served shape is ModelConfig()
+BENCH_CONFIG = ModelConfig(vocab=32768, seq_len=512, d_model=1024,
+                           n_heads=16, d_ff=4096, n_layers=8)
+BENCH_BATCH = 32
 
 
 def init_params(cfg: ModelConfig, key: jax.Array) -> dict[str, Any]:
@@ -125,6 +134,26 @@ def use_flash() -> bool:
     return env_flag("TASKSRUNNER_FLASH")
 
 
+def _flash_core(q, k, v, mesh: Mesh | None) -> jax.Array:
+    """The Pallas flash kernel, placed per device under a mesh.
+
+    GSPMD cannot split a Mosaic call, so on a mesh the kernel runs
+    inside ``shard_map``: batch on ``dp`` and heads on ``tp`` where the
+    mesh has the axis and it divides the dimension, replicated
+    otherwise (a serving bucket smaller than the mesh)."""
+    from tasksrunner.ml.flash import flash_attention
+    if mesh is None:
+        return flash_attention(q, k, v)
+
+    def axis(name: str, dim: int) -> str | None:
+        ok = name in mesh.axis_names and dim % mesh.shape[name] == 0
+        return name if ok else None
+
+    spec = P(axis("dp", q.shape[0]), None, axis("tp", q.shape[2]), None)
+    return jax.shard_map(flash_attention, mesh=mesh, in_specs=(spec,) * 3,
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+
 def _attention(x: jax.Array, layer: dict, cfg: ModelConfig,
                mesh: Mesh | None = None) -> jax.Array:
     b, s, _ = x.shape
@@ -138,8 +167,7 @@ def _attention(x: jax.Array, layer: dict, cfg: ModelConfig,
         from tasksrunner.ml.ring import ring_attention
         ctx = ring_attention(q, k, v, mesh=mesh)          # [b, s, h, dh]
     elif use_flash():
-        from tasksrunner.ml.flash import flash_attention
-        ctx = flash_attention(q, k, v)                    # Pallas kernel
+        ctx = _flash_core(q, k, v, mesh)                  # Pallas kernel
     else:
         logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.bfloat16),
                             k.astype(jnp.bfloat16),
@@ -155,9 +183,10 @@ def forward(params: dict, tokens: jax.Array, *, cfg: ModelConfig,
             mesh: Mesh | None = None) -> jax.Array:
     """tokens [batch, seq] int32 → class logits [batch, n_classes].
 
-    ``mesh`` only changes which attention core runs (ring under an
-    ``sp`` axis); everything else is plain GSPMD — the same code jits
-    single-chip and multi-chip."""
+    ``mesh`` only changes how the attention core is placed (ring under
+    an ``sp`` axis, the flash kernel per device otherwise); everything
+    else is plain GSPMD — the same code jits single-chip and
+    multi-chip."""
     x = (params["embed"][tokens] + params["pos"][None, :, :]).astype(act_dtype())
     for layer in params["layers"]:
         x = x + _attention(_layernorm(x, layer["ln1"]), layer, cfg, mesh)

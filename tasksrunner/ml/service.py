@@ -16,19 +16,23 @@ that participates in the same building blocks as every other service.
   same topic.
 * ``GET /scores/{task_id}`` — read a stored score back.
 * ``GET /ml/stats`` — serving-plane introspection: queue depth,
-  per-bucket batch counts, and the jit cache size (flat after warmup
-  == zero recompiles; the bench and tests assert on it).
+  per-bucket batch counts, the jit cache size (flat after warmup
+  == zero recompiles; the bench and tests assert on it), the warmup's
+  seconds, and where the model runs (platform, device kind, device
+  count).
 
 Serving runs on the continuous-batching engine
 (:mod:`tasksrunner.ml.batching`): requests queue, micro-batches
 assemble under the ``TASKSRUNNER_ML_MAX_DELAY_MS`` budget, batch
 shapes pad up a fixed bucket ladder, and each bucket jit-compiles
-exactly once at startup warmup. Params are device-put once — fully
-replicated over a 1-D data mesh when >1 device is visible, with the
-batch dimension sharded over the mesh for bucket sizes the device
-count divides. The batcher's tokens-in-flight ratio registers with
-the admission controller, so floods shed 429+Retry-After at the front
-door, and its ``ml_*`` histograms feed the target-p99 autoscale rule.
+exactly once at startup warmup, with JAX's persistent compile cache
+placed by :mod:`tasksrunner.ml.compile_cache`. Params are device-put
+once — fully replicated over a 1-D data mesh when >1 device is
+visible, with the batch dimension sharded over the mesh for bucket
+sizes the device count divides. The batcher's tokens-in-flight ratio
+registers with the admission controller, so floods shed
+429+Retry-After at the front door, and its ``ml_*`` histograms feed
+the target-p99 autoscale rule.
 
 During warmup both lanes answer a retryable not-ready — 503 with a
 ``Retry-After`` header. The runtime turns that header into a
@@ -41,6 +45,7 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import time
 
 from tasksrunner.app import App, Response
 from tasksrunner.errors import SaturatedError
@@ -55,6 +60,28 @@ PRIORITY_LABELS = ["backlog", "low", "normal", "high", "urgent"]
 WARMUP_RETRY_AFTER = 1
 
 
+def encode_task(task: dict, cfg) -> list[int]:
+    """The token row a task is scored on: its text fields, hashed."""
+    from tasksrunner.ml.model import hash_token_ids
+
+    text = " ".join(
+        str(task.get(k, "")) for k in
+        ("taskName", "taskCreatedBy", "taskAssignedTo") if task.get(k))
+    return hash_token_ids(text or "empty", cfg)
+
+
+def serving_fn(cfg, mesh):
+    """The served computation, one jit region: tokens → class
+    probabilities. Softmax inside the region means one device→host
+    transfer per batch, of exactly the probabilities."""
+    import jax
+
+    from tasksrunner.ml.model import forward
+
+    return jax.jit(lambda p, t: jax.nn.softmax(
+        forward(p, t, cfg=cfg, mesh=mesh), axis=-1))
+
+
 def make_app(*, pubsub: str = "taskspubsub", topic: str = "tasksavedtopic",
              state_store: str = "scores") -> App:
     import jax
@@ -64,9 +91,9 @@ def make_app(*, pubsub: str = "taskspubsub", topic: str = "tasksavedtopic",
 
     from tasksrunner.envflag import env_flag
     from tasksrunner.ml.batching import BatcherConfig, MicroBatcher
+    from tasksrunner.ml.compile_cache import enable_compile_cache
     from tasksrunner.ml.model import (
-        ModelConfig, forward, hash_token_ids, init_params, replicate_params,
-        serving_mesh,
+        ModelConfig, init_params, replicate_params, serving_mesh,
     )
     from tasksrunner.observability import admission
 
@@ -111,6 +138,8 @@ def make_app(*, pubsub: str = "taskspubsub", topic: str = "tasksavedtopic",
     @app.on_startup
     async def load_model():
         def build():
+            t0 = time.perf_counter()
+            enable_compile_cache()
             params = init_params(cfg, jax.random.PRNGKey(0))
             mesh = serving_mesh()
             # device-resident once: replicated over the data mesh (or
@@ -118,10 +147,7 @@ def make_app(*, pubsub: str = "taskspubsub", topic: str = "tasksavedtopic",
             # re-feed weights
             params = (replicate_params(params, mesh) if mesh is not None
                       else jax.device_put(params))
-            # softmax inside the jit region: one device→host transfer
-            # per batch, of exactly the probabilities
-            fn = jax.jit(lambda p, t: jax.nn.softmax(
-                forward(p, t, cfg=cfg), axis=-1))
+            fn = serving_fn(cfg, mesh)
             compiled["mesh"] = mesh
             # warm every bucket so no request ever pays an XLA compile
             # — after this loop the jit cache must stay flat
@@ -129,6 +155,13 @@ def make_app(*, pubsub: str = "taskspubsub", topic: str = "tasksavedtopic",
                 fn(params, _place_tokens(
                     np.zeros((bucket, cfg.seq_len), np.int32))
                    ).block_until_ready()
+            devices = jax.devices()
+            compiled["where"] = {
+                "platform": devices[0].platform,
+                "device_kind": devices[0].device_kind,
+                "device_count": len(devices),
+                "warmup_s": time.perf_counter() - t0,
+            }
             return params, fn
 
         # compile off the event loop: the server/sidecar are already up,
@@ -156,14 +189,9 @@ def make_app(*, pubsub: str = "taskspubsub", topic: str = "tasksavedtopic",
         return Response(429, {"error": str(exc)},
                         headers={"Retry-After": str(int(exc.retry_after or 1))})
 
-    def _encode(task: dict) -> np.ndarray:
-        text = " ".join(
-            str(task.get(k, "")) for k in
-            ("taskName", "taskCreatedBy", "taskAssignedTo") if task.get(k))
-        return np.asarray(hash_token_ids(text or "empty", cfg), np.int32)
-
     async def _score(task: dict) -> dict:
-        return await batcher.submit(_encode(task))
+        return await batcher.submit(np.asarray(encode_task(task, cfg),
+                                               np.int32))
 
     @app.post("/score")
     async def score(req):
@@ -212,6 +240,7 @@ def make_app(*, pubsub: str = "taskspubsub", topic: str = "tasksavedtopic",
         stats["ready"] = "fn" in compiled
         fn = compiled.get("fn")
         stats["jit_cache_size"] = int(fn._cache_size()) if fn is not None else 0
+        stats.update(compiled.get("where", {}))
         return stats
 
     return app

@@ -6,23 +6,12 @@ import asyncio
 import pathlib
 import sys
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import pathlib as _pathlib
-import sys as _sys
-
-_sys.path.insert(0, str(_pathlib.Path(__file__).resolve().parent.parent))
-
-from tasksrunner.ml.platform import pin_cpu_platform  # noqa: E402
-
-if not pin_cpu_platform():
-    pytest.skip("jax cpu platform unavailable", allow_module_level=True)
-
-jax = pytest.importorskip("jax")
-import jax.numpy as jnp  # noqa: E402
-
-from tasksrunner.ml.model import (  # noqa: E402
+from tasksrunner.ml.model import (
     ModelConfig,
     forward,
     hash_tokens,
